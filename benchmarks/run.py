@@ -118,6 +118,8 @@ def main() -> None:
         ap.error("--full and --quick are mutually exclusive")
     if args.host_tuned and os.environ.get(_TUNED_GUARD) != "1":
         _reexec_host_tuned()
+    from repro.launch import cache
+    cache.enable_compile_cache()
     quick = not args.full
     only = set(args.only.split(",")) if args.only else None
 

@@ -41,14 +41,10 @@ scan-carry tensors (EF residual, stream stats) at reduced precision.
 """
 
 import argparse
-import functools
-
-import jax
 
 from repro import sweep
-from repro.core import compression, federated, scheduler, streaming, \
-    wireless
-from repro.data import partition, synthetic
+from repro.core import compression, federated, streaming
+from repro.launch import cache, paper
 from repro.models import paper_nets
 
 
@@ -97,19 +93,24 @@ def main() -> None:
                     help="storage dtype for the big scan-carry tensors")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    cache.enable_compile_cache()
 
-    shards = 1200 if args.full_data else 300
-    spc = 6000 if args.full_data else 2000
-    imgs, labels = synthetic.generate(args.seed, samples_per_class=spc)
-    data = partition.partition(
-        imgs, labels, seed=args.seed + 1,
-        spec=partition.PartitionSpec(num_devices=args.devices,
-                                     num_shards=shards, shard_size=50))
-    wcfg = wireless.WirelessConfig(model_bits=args.model_bits)
-
-    mspec = paper_nets.PaperNetSpec(kind=args.model)
-    params = paper_nets.init(jax.random.key(args.seed + 3), mspec)
-    print(f"[feel] {args.model} ({paper_nets.num_params(params):,} "
+    stream_cfg = streaming.StreamConfig(
+        process=args.stream, rate=args.stream_rate) if args.stream \
+        else None
+    comp_cfg = compression.CompressionConfig(
+        codec=args.codec, bit_width=args.bit_width) if args.codec \
+        else None
+    setup = paper.paper_setup(
+        model=args.model, method=args.method, rounds=args.rounds,
+        devices=args.devices, n_fixed=args.n_fixed, epochs=args.epochs,
+        model_bits=args.model_bits, full_data=args.full_data,
+        seed=args.seed,
+        staleness_weight=args.staleness_weight if args.stream else 0.0,
+        stream=stream_cfg, compression=comp_cfg,
+        dispatch_cap=args.dispatch_cap or None,
+        carry_dtype=args.carry_dtype or None)
+    print(f"[feel] {args.model} ({paper_nets.num_params(setup.params):,} "
           f"params), K={args.devices}, method={args.method}, "
           f"E={args.epochs}, s={args.model_bits / 1e3:.0f} kbit, "
           f"S={args.scenarios}"
@@ -117,34 +118,15 @@ def main() -> None:
              if args.stream else "")
           + (f", codec={args.codec}" if args.codec else ""))
 
-    scfg = scheduler.SchedulerConfig(
-        method=args.method, n_min=1,
-        n_fixed=args.n_fixed or None, iterations_max=6,
-        staleness_weight=args.staleness_weight if args.stream else 0.0)
-    stream_cfg = streaming.StreamConfig(
-        process=args.stream, rate=args.stream_rate) if args.stream \
-        else None
-    comp_cfg = compression.CompressionConfig(
-        codec=args.codec, bit_width=args.bit_width) if args.codec \
-        else None
-    fcfg = federated.FLConfig(
-        num_rounds=args.rounds, local_epochs=args.epochs, batch_size=50,
-        learning_rate=0.1 if args.model == "mlp" else 0.05,
-        stream=stream_cfg, compression=comp_cfg,
-        dispatch_cap=args.dispatch_cap or None,
-        carry_dtype=args.carry_dtype or None)
-    loss_fn = functools.partial(paper_nets.loss_fn, spec=mspec)
-    eval_fn = functools.partial(paper_nets.accuracy, spec=mspec)
-
     if args.scenarios > 1:
         spec = sweep.SweepSpec(
-            fl=fcfg, sched=scfg, wireless=wcfg,
+            fl=setup.fcfg, sched=setup.scfg, wireless=setup.wcfg,
             scenarios_per_point=args.scenarios,
             chunk_scenarios=args.chunk_scenarios,
             base_seed=args.seed)
         results = sweep.run_sweep(
-            spec, data=data, loss_fn=loss_fn, eval_fn=eval_fn,
-            init_params=params,
+            spec, data=setup.data, loss_fn=setup.loss_fn,
+            eval_fn=setup.eval_fn, init_params=setup.params,
             ckpt_path=args.sweep_ckpt or None,
             jsonl_path=args.sweep_jsonl or None)
         _, summary = results[0]
@@ -164,12 +146,10 @@ def main() -> None:
               f"(std={float(final['std']):.4f})")
         return
 
-    net = wireless.sample_network(jax.random.key(args.seed + 2),
-                                  args.devices, wcfg)
     _, hist = federated.run_federated(
-        init_params=params, loss_fn=loss_fn, eval_fn=eval_fn,
-        data=data, net=net, wcfg=wcfg, scfg=scfg, fcfg=fcfg,
-        key=jax.random.key(args.seed + 4))
+        init_params=setup.params, loss_fn=setup.loss_fn,
+        eval_fn=setup.eval_fn, data=setup.data, net=setup.net,
+        wcfg=setup.wcfg, scfg=setup.scfg, fcfg=setup.fcfg, key=setup.key)
 
     e_tot = t_tot = 0.0
     for r in hist:

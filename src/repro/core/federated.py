@@ -1183,20 +1183,19 @@ def make_feel_sim_batch(*, loss_fn: Callable, eval_fn: Callable,
                                   None, None, None, None,
                                   None, None, None, 0, 0))
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
         sharded = jax.sharding.PartitionSpec(scenario_axis)
         rep = jax.sharding.PartitionSpec()
         # Telemetry adds a third (frames) output with the same leading
         # scenario axis as params/metrics — sharded identically.
         n_out = 3 if telemetry_lib.active(fcfg.telemetry) is not None \
             else 2
-        vsim = shard_map(
+        vsim = jax.shard_map(
             vsim, mesh=mesh,
             in_specs=(sharded if donate_params else rep,
                       rep, rep, rep, rep, rep, rep, rep,
                       sharded, sharded),
             out_specs=(sharded,) * n_out,
-            check_rep=False)
+            check_vma=False)
     return jax.jit(vsim, donate_argnums=(0,) if donate_params else ())
 
 

@@ -16,11 +16,15 @@ lane); each program owns one scenario — ``(K, P)`` update / residual
 blocks plus ``(K,)`` width and selection rows (quant additionally
 streams a ``(K, P)`` noise block; topk takes a ``(K,)`` placeholder row
 instead — it never reads noise, and a dead full block would cost real
-VMEM traffic).  At paper scale
-(K = 100, P ~ 12.7k MLP coordinates) that is ~25 MB of f32 blocks —
-fine for the interpret-mode validation path this repo runs on CPU, but
-a real-TPU launch at production P needs a P-blocked variant carrying
-the row max / threshold in SMEM across P-tiles (ROADMAP open item).
+VMEM traffic).  Whole ``(K, P)`` blocks do not fit a TPU's VMEM at the
+paper's widths: for a v5e the compiler refuses the quant launch at
+K = 100 with P = 21,840 (CNN; a 31 MB scoped allocation against the
+16 MB scoped limit) and with P = 159,010 (MLP; 505 MB of the 128 MB
+VMEM).  On a TPU that refusal is the error a caller of
+``CompressionConfig(use_kernel=True)`` gets; the kernel runs only in
+interpret mode, as the CPU oracle tests do.  A TPU launch needs a
+P-blocked variant carrying the row max / threshold in SMEM across
+P-tiles (ROADMAP A3).
 The per-element work is VPU-only (abs/floor/compare), so the kernel is
 bandwidth-bound and fusing removes the three extra round trips.
 
@@ -87,7 +91,7 @@ def compress_update_kernel(updates: jax.Array, residual: jax.Array,
                            widths: jax.Array, selected: jax.Array,
                            noise: jax.Array, *, mode: str, keep: int = 0,
                            thresh_iters: int = DEFAULT_THRESH_ITERS,
-                           interpret: bool = True
+                           interpret: bool
                            ) -> tuple[jax.Array, jax.Array]:
     """Batched fused compress: ``(S, K, P)`` updates/residual/noise +
     ``(S, K)`` widths/selection -> ``((S, K, P) decoded values,

@@ -14,6 +14,8 @@ from HBM.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -33,11 +35,10 @@ def _diversity_kernel(labels_ref, mask_ref, out_ref, *, num_classes: int):
     out_ref[...] = jnp.stack([gini, shannon, total])[None, :]
 
 
-def diversity_kernel(labels: jax.Array, mask: jax.Array, num_classes: int,
-                     interpret: bool = True) -> jax.Array:
+def diversity_kernel(labels: jax.Array, mask: jax.Array, num_classes: int, *,
+                     interpret: bool) -> jax.Array:
     """labels/mask: (K, N) -> (K, 3) [gini, shannon, count]."""
     k, n = labels.shape
-    import functools
     return pl.pallas_call(
         functools.partial(_diversity_kernel, num_classes=num_classes),
         grid=(k,),

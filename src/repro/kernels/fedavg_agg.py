@@ -28,8 +28,8 @@ def _fedavg_kernel(updates_ref, weights_ref, out_ref):
 
 
 def fedavg_agg_kernel(updates: jax.Array, weights: jax.Array,
-                      block_p: int = DEFAULT_BLOCK_P,
-                      interpret: bool = True) -> jax.Array:
+                      block_p: int = DEFAULT_BLOCK_P, *,
+                      interpret: bool) -> jax.Array:
     """updates: (K, P) with P % block_p == 0; weights: (K,) -> (P,)."""
     k, p = updates.shape
     grid = (p // block_p,)
@@ -57,8 +57,8 @@ def _fedavg_stale_kernel(updates_ref, weights_ref, mask_ref, stale_ref,
 
 def fedavg_agg_stale_kernel(updates: jax.Array, weights: jax.Array,
                             mask: jax.Array, stale_w: jax.Array,
-                            block_p: int = DEFAULT_BLOCK_P,
-                            interpret: bool = True) -> jax.Array:
+                            block_p: int = DEFAULT_BLOCK_P, *,
+                            interpret: bool) -> jax.Array:
     """Staleness-weighted masked FedAvg reduction (event subsystem,
     DESIGN.md §12).
 
@@ -99,8 +99,8 @@ def _fedavg_masked_kernel(updates_ref, weights_ref, mask_ref, out_ref):
 
 def fedavg_agg_masked_kernel(updates: jax.Array, weights: jax.Array,
                              mask: jax.Array,
-                             block_p: int = DEFAULT_BLOCK_P,
-                             interpret: bool = True) -> jax.Array:
+                             block_p: int = DEFAULT_BLOCK_P, *,
+                             interpret: bool) -> jax.Array:
     """Failure-masked FedAvg reduction (fault subsystem, DESIGN.md §10).
 
     ``out[p] = sum_k w[k] * m[k] * updates[k, p]`` — the unmasked

@@ -91,7 +91,7 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int = 0,
                            block_q: int = 128, block_k: int = 128,
                            kv_len: int | None = None,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """q: (BH, Sq, hd); k, v: (BH, Skv, hd) — flattened batch*head rows.
 
     Sq/Skv must be multiples of the block sizes (ops.py pads); ``kv_len``

@@ -1,9 +1,16 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handles padding to block multiples, layout (B, S, H, hd) <-> kernel
-(BH, S, hd), GQA head expansion, and the interpret-mode switch (True off
-TPU so the kernels validate on CPU; on real TPU backends pass
-``interpret=False``).
+(BH, S, hd), GQA head expansion, and the interpret-mode switch.
+
+This module is the one place that decides interpret mode: every wrapper
+defaults ``interpret=None``, which compiles the kernel when the default
+backend is a TPU and interprets it everywhere else (the CPU tests).  The
+raw kernel entries under ``repro.kernels`` take ``interpret`` as a
+required keyword, so no caller runs the interpreter on a TPU by default.
+A caller that pins work to the host CPU on a TPU machine
+(``jax.default_device``) still sees ``default_backend() == "tpu"`` here,
+so it keeps the kernel lanes off rather than relying on this switch.
 """
 
 from __future__ import annotations

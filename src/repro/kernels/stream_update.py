@@ -35,9 +35,9 @@ def _stream_update_kernel(h_ref, d_ref, arr_ref, stale_ref, sel_ref,
                           decay: float, size_cap: float):
     h0 = h_ref[0]                                   # (K, C)
     d = d_ref[0]                                    # (K, C)
-    arrivals = arr_ref[0]                           # (K,)
-    stale = stale_ref[0]                            # (K,)
-    sel = sel_ref[0]                                # (K,)
+    arrivals = arr_ref[0, 0]                        # (K,)
+    stale = stale_ref[0, 0]                         # (K,)
+    sel = sel_ref[0, 0]                             # (K,)
     h = jnp.maximum(h0 + d, 0.0)
     if size_cap > 0.0:
         total = jnp.sum(h, axis=-1, keepdims=True)
@@ -52,14 +52,14 @@ def _stream_update_kernel(h_ref, d_ref, arr_ref, stale_ref, sel_ref,
     h_out[...] = h[None]
     stats_out[...] = jnp.stack([gini, shannon, sizes], axis=-1)[None]
     stale_out[...] = (jnp.where(sel > 0.0, 0.0, decay * stale)
-                      + arrivals)[None]
+                      + arrivals)[None, None]
 
 
 def stream_update_kernel(hists: jax.Array, deltas: jax.Array,
                          arrivals: jax.Array, staleness: jax.Array,
                          selected: jax.Array, *,
                          decay: float, size_cap: float = 0.0,
-                         interpret: bool = True
+                         interpret: bool
                          ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Batched fused refresh: ``(S, K, C)`` counts/deltas + ``(S, K)``
     arrivals/staleness/selection -> ``((S, K, C) counts, (S, K, 3)
@@ -76,14 +76,18 @@ def stream_update_kernel(hists: jax.Array, deltas: jax.Array,
     kern = functools.partial(_stream_update_kernel, decay=decay,
                              size_cap=size_cap)
     mat = pl.BlockSpec((1, k, c), lambda i: (i, 0, 0))
-    row = pl.BlockSpec((1, k), lambda i: (i, 0))
-    return pl.pallas_call(
+    # Rows travel as (S, 1, K): a TPU block's last two dims must be
+    # (8, 128)-aligned or whole, and a (1, K) slice of (S, K) is neither.
+    row = pl.BlockSpec((1, 1, k), lambda i: (i, 0, 0))
+    h, stats, stale = pl.pallas_call(
         kern,
         grid=(s,),
         in_specs=[mat, mat, row, row, row],
         out_specs=[mat, pl.BlockSpec((1, k, 3), lambda i: (i, 0, 0)), row],
         out_shape=[jax.ShapeDtypeStruct((s, k, c), jnp.float32),
                    jax.ShapeDtypeStruct((s, k, 3), jnp.float32),
-                   jax.ShapeDtypeStruct((s, k), jnp.float32)],
+                   jax.ShapeDtypeStruct((s, 1, k), jnp.float32)],
         interpret=interpret,
-    )(hists, deltas, arrivals, staleness, selected)
+    )(hists, deltas, arrivals[:, None], staleness[:, None],
+      selected[:, None])
+    return h, stats, stale[:, 0]

@@ -42,11 +42,11 @@ def _sub2_pgd_kernel(sel_ref, tt_ref, c_ref, pw_ref, bits_ref, a0_ref,
                      alpha_ref, obj_ref, *, rho: float, lr: float,
                      tau: float, iters: int, bandwidth_hz: float,
                      min_alpha: float, proj_iters: int):
-    mask = sel_ref[0]                                  # (K,)
-    tt = tt_ref[0]
-    c = c_ref[0]
-    pw = pw_ref[0]
-    bits = bits_ref[0]                                 # (K,) payload bits
+    mask = sel_ref[0, 0]                               # (K,)
+    tt = tt_ref[0, 0]
+    c = c_ref[0, 0]
+    pw = pw_ref[0, 0]
+    bits = bits_ref[0, 0]                              # (K,) payload bits
     a0 = a0_ref[0]                                     # (N_STARTS, K)
     n_act = jnp.maximum(jnp.sum(mask), 1.0)
     any_act = jnp.sum(mask) > 0.5
@@ -127,8 +127,8 @@ def _sub2_pgd_kernel(sel_ref, tt_ref, c_ref, pw_ref, bits_ref, a0_ref,
     a, best_a, best_o = jax.lax.fori_loop(0, iters, body,
                                           (a, a, exact_obj(a)))
     pick = best_o[0] <= best_o[1]
-    alpha_ref[...] = jnp.where(pick, best_a[0], best_a[1])[None, :]
-    obj_ref[...] = jnp.where(pick, best_o[0], best_o[1])[None, None]
+    alpha_ref[...] = jnp.where(pick, best_a[0], best_a[1])[None, None, :]
+    obj_ref[...] = jnp.where(pick, best_o[0], best_o[1])[None, None, None]
 
 
 def sub2_pgd_kernel(selected: jax.Array, t_train: jax.Array,
@@ -138,7 +138,7 @@ def sub2_pgd_kernel(selected: jax.Array, t_train: jax.Array,
                     tau: float, iters: int, bandwidth_hz: float,
                     min_alpha: float,
                     proj_iters: int = DEFAULT_PROJ_ITERS,
-                    interpret: bool = True
+                    interpret: bool
                     ) -> tuple[jax.Array, jax.Array]:
     """Batched fused PGD: (S, K) instance rows -> ((S, K) alpha, (S,) obj).
 
@@ -154,15 +154,18 @@ def sub2_pgd_kernel(selected: jax.Array, t_train: jax.Array,
         _sub2_pgd_kernel, rho=rho, lr=lr, tau=tau, iters=iters,
         bandwidth_hz=bandwidth_hz, min_alpha=min_alpha,
         proj_iters=proj_iters)
-    row = pl.BlockSpec((1, k), lambda i: (i, 0))
+    # Rows travel as (S, 1, K): a TPU block's last two dims must be
+    # (8, 128)-aligned or whole, and a (1, K) slice of (S, K) is neither.
+    row = pl.BlockSpec((1, 1, k), lambda i: (i, 0, 0))
     alpha, obj = pl.pallas_call(
         kern,
         grid=(s,),
         in_specs=[row, row, row, row, row,
                   pl.BlockSpec((1, N_STARTS, k), lambda i: (i, 0, 0))],
-        out_specs=[row, pl.BlockSpec((1, 1), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((s, k), jnp.float32),
-                   jax.ShapeDtypeStruct((s, 1), jnp.float32)],
+        out_specs=[row, pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((s, 1, k), jnp.float32),
+                   jax.ShapeDtypeStruct((s, 1, 1), jnp.float32)],
         interpret=interpret,
-    )(selected, t_train, snr_coeff, tx_power, payload_bits, alpha0)
-    return alpha, obj[:, 0]
+    )(*(x[:, None, :] for x in (selected, t_train, snr_coeff, tx_power,
+                                payload_bits)), alpha0)
+    return alpha[:, 0], obj[:, 0, 0]

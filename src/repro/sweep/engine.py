@@ -354,10 +354,13 @@ class SweepEngine:
 
     # -- execution -------------------------------------------------------
 
-    def run_chunk(self, point: grid_lib.GridPoint, global_start: int,
-                  size: int, agg):
-        """Run scenarios [global_start, global_start + size) of a grid
-        point and fold their metrics into ``agg``."""
+    def chunk_outputs(self, point: grid_lib.GridPoint, global_start: int,
+                      size: int):
+        """The batch sim's raw outputs for scenarios [global_start,
+        global_start + size): ``(params, metrics[, frames])`` with a
+        leading ``(size,)`` axis, from the program :meth:`run_chunk`
+        folds — per-scenario values the O(R) aggregate no longer holds.
+        """
         data = self.data
         indices = jnp.arange(global_start, global_start + size)
         nets = wireless.sample_networks_indexed(
@@ -366,9 +369,15 @@ class SweepEngine:
         params = federated.tile_params(self.init_params, size) \
             if self.donate_params else self.init_params
         sim = self._sim_for(point, size)
-        out = sim(params, data.images, data.labels, data.mask,
-                  data.sizes, self._hists_for(point), self._test_x,
-                  data.test_labels, nets, keys)
+        return sim(params, data.images, data.labels, data.mask,
+                   data.sizes, self._hists_for(point), self._test_x,
+                   data.test_labels, nets, keys)
+
+    def run_chunk(self, point: grid_lib.GridPoint, global_start: int,
+                  size: int, agg):
+        """Run scenarios [global_start, global_start + size) of a grid
+        point and fold their metrics into ``agg``."""
+        out = self.chunk_outputs(point, global_start, size)
         if len(out) == 3:
             _, metrics, frames = out
             self._sink_frames(point, global_start, size, metrics, frames)
