@@ -21,10 +21,9 @@ round-event log for ``python -m repro.telemetry.report``),
 kernels (Pallas micro), roofline (requires dryrun_results.json from
 repro.launch.dryrun).
 
-``--profile DIR`` wraps the selected suites in ``jax.profiler.trace``
-and emits a ``profile/phases_seen`` row naming which ``repro/*`` named
-scopes (schedule, local_train, aggregate, stream_refresh) the drivers
-entered — the CI profiler smoke asserts all four.
+``--profile DIR`` wraps the selected suites in ``jax.profiler.trace``;
+the trace's device ops carry the drivers' ``repro/*`` named scopes
+(DESIGN.md §13).
 
 ``--host-tuned`` re-execs the process with the host-tuning idioms the
 related training repos bake into their launchers (SNIPPETS.md §1-2):
@@ -97,9 +96,7 @@ def main() -> None:
     ap.add_argument("--dryrun-json", default="dryrun_results.json")
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="wrap the selected suites in jax.profiler.trace"
-                         "(DIR) and report which repro/* named phases "
-                         "(schedule, local_train, aggregate, "
-                         "stream_refresh) were entered")
+                         "(DIR)")
     ap.add_argument("--telemetry-log", default=None, metavar="PATH",
                     help="with the telemetry suite: sink the enabled "
                          "run's round frames to this JSONL file (the CI "
@@ -219,18 +216,13 @@ def main() -> None:
                       f"(run repro.launch.dryrun first)", file=sys.stderr)
 
     # try/finally so a suite raising mid-run still finalizes the
-    # profiler trace directory and emits phases_seen — a half-written
-    # trace dir with no closing __exit__ is unreadable by the viewer.
+    # profiler trace directory — a half-written trace dir with no
+    # closing __exit__ is unreadable by the viewer.
     try:
         run_suites()
     finally:
         if profile_ctx is not None:
             profile_ctx.__exit__(None, None, None)
-            from repro import telemetry
-            seen = sorted(telemetry.seen_phases())
-            _emit(("profile/phases_seen", len(seen),
-                   "named_scopes " + "+".join(seen) if seen else
-                   "named_scopes none"))
             print(f"# profiler trace written to {args.profile}",
                   file=sys.stderr)
 

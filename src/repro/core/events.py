@@ -312,7 +312,6 @@ def _make_event_sim(loss_fn: Callable, eval_fn: Callable, wcfg, scfg,
     num_events = ecfg.num_events or fcfg.num_rounds
 
     trainer = fed.make_local_trainer(loss_fn, fcfg)
-    max_steps = fed._max_local_steps(fcfg, capacity)
     sch = fed._sched_cfg(scfg, fcfg)
     do_eval = jnp.asarray(fed._eval_mask(num_events, eval_every))
     ticks = jnp.arange(num_events, dtype=jnp.int32)
@@ -340,6 +339,7 @@ def _make_event_sim(loss_fn: Callable, eval_fn: Callable, wcfg, scfg,
             net: wireless.NetworkState, key: Array
             ) -> Tuple[Params, "fed.RoundMetrics"]:
         k_dev = sizes.shape[0]
+        lanes, max_steps = fed.local_train_launch(fcfg, k_dev, capacity)
         p_flat = fed.flat_param_size(params)
         # Once-per-scenario draws off the *pristine* scenario key —
         # folded before the streaming init split, exactly like the
@@ -422,7 +422,7 @@ def _make_event_sim(loss_fn: Callable, eval_fn: Callable, wcfg, scfg,
                 n_dropped = jnp.zeros((), jnp.int32)
             else:
                 didx, selected, n_dropped = fed.dispatch_plan(selected,
-                                                              n_cap)
+                                                              lanes)
             # Fault draw + realized accounting, plus the per-device
             # completion times the arrival queue needs (recomputed with
             # the synchronous drivers' own expressions, so the cohort
@@ -587,12 +587,13 @@ def _make_event_sim(loss_fn: Callable, eval_fn: Callable, wcfg, scfg,
             ages = jnp.where(ok > 0.0, 0, ages + 1)
             if flt is not None:
                 rel = faults.reliability_update(rel, selected, ok, flt)
-            acc = jax.lax.cond(
-                do_ev,
-                lambda p: jnp.asarray(eval_fn(p, test_x, test_labels),
-                                      jnp.float32),
-                lambda p: jnp.full((), jnp.nan, jnp.float32),
-                params)
+            with telemetry_lib.phase_scope("eval"):
+                acc = jax.lax.cond(
+                    do_ev,
+                    lambda p: jnp.asarray(eval_fn(p, test_x, test_labels),
+                                          jnp.float32),
+                    lambda p: jnp.full((), jnp.nan, jnp.float32),
+                    params)
             met = fed.RoundMetrics(
                 accuracy=acc,
                 n_selected=jnp.sum(selected).astype(jnp.int32),

@@ -239,18 +239,22 @@ def make_local_trainer(loss_fn: Callable[[Params, Array, Array, Array],
         def step(carry, inp):
             p, vel = carry
             k, active = inp
-            idx = jax.random.randint(k, (cfg.batch_size,), 0, cap)
-            bx = synthetic.to_float(images[idx])
-            by = labels[idx]
-            bm = mask[idx]
-            g = jax.grad(loss_fn)(p, bx, by, bm)
-            vel = jax.tree_util.tree_map(
-                lambda v, gi: cfg.momentum * v + gi, vel, g)
-            p_new = jax.tree_util.tree_map(
-                lambda w, v: w - cfg.learning_rate * v, p, vel)
-            p = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(active > 0.0, new, old),
-                p_new, p)
+            with telemetry_lib.part_scope("batch"):
+                idx = jax.random.randint(k, (cfg.batch_size,), 0, cap)
+                bx = synthetic.to_float(images[idx])
+                by = labels[idx]
+                bm = mask[idx]
+            # The gradient's part holds the SGD update too: every op of
+            # the step but the minibatch's.
+            with telemetry_lib.part_scope("grad"):
+                g = jax.grad(loss_fn)(p, bx, by, bm)
+                vel = jax.tree_util.tree_map(
+                    lambda v, gi: cfg.momentum * v + gi, vel, g)
+                p_new = jax.tree_util.tree_map(
+                    lambda w, v: w - cfg.learning_rate * v, p, vel)
+                p = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(active > 0.0, new, old),
+                    p_new, p)
             return (p, vel), None
 
         vel0 = jax.tree_util.tree_map(jnp.zeros_like, params)
@@ -548,6 +552,25 @@ def _train_round_faulty(trainer: Callable, max_steps: int, cfg: FLConfig,
 def _max_local_steps(cfg: FLConfig, capacity: int) -> int:
     steps_per_epoch = max(1, -(-capacity // cfg.batch_size))
     return cfg.local_epochs * steps_per_epoch
+
+
+def local_train_launch(cfg: FLConfig, num_devices: int,
+                       capacity: int) -> Tuple[int, int]:
+    """``(lanes, max_steps)`` of the vmapped local trainer each round.
+
+    The trainer runs ``lanes`` lanes — K, or ``min(dispatch_cap, K)`` on
+    the dense-block path — for ``max_steps`` scan steps each, whatever
+    the schedule admits: frozen lanes and a device's steps beyond its
+    own ``E * ceil(size_k / B)`` are masked, not skipped.  The drivers
+    build their trainer and dispatch plan from this, and the chip
+    benchmark's ``lane_step_use`` takes the ``lanes * max_steps``
+    lane-steps a scenario-round launches from it.  A change that skips
+    lanes or steps must change this function with it, so that the
+    count stays equal to what the program launches.
+    """
+    lanes = num_devices if cfg.dispatch_cap is None \
+        else min(int(cfg.dispatch_cap), num_devices)
+    return lanes, _max_local_steps(cfg, capacity)
 
 
 # ---------------------------------------------------------------------------
@@ -866,7 +889,6 @@ def _make_sim(loss_fn: Callable, eval_fn: Callable, wcfg, scfg, fcfg,
     carry simply holds both extras.
     """
     trainer = make_local_trainer(loss_fn, fcfg)
-    max_steps = _max_local_steps(fcfg, capacity)
     sch = _sched_cfg(scfg, fcfg)
     do_eval = jnp.asarray(_eval_mask(fcfg.num_rounds, eval_every))
     n_cap = fcfg.dispatch_cap
@@ -890,6 +912,7 @@ def _make_sim(loss_fn: Callable, eval_fn: Callable, wcfg, scfg, fcfg,
             net: wireless.NetworkState, key: Array
             ) -> Tuple[Params, RoundMetrics]:
         k_dev = sizes.shape[0]
+        lanes, max_steps = local_train_launch(fcfg, k_dev, capacity)
         # Chronic per-device drop rates (DESIGN.md §10): drawn once per
         # scenario off the *pristine* scenario key (folded, before the
         # streaming init split, so every other stream is untouched) and
@@ -965,7 +988,7 @@ def _make_sim(loss_fn: Callable, eval_fn: Callable, wcfg, scfg, fcfg,
                 didx = None
                 n_dropped = jnp.zeros((), jnp.int32)
             else:
-                didx, selected, n_dropped = dispatch_plan(selected, n_cap)
+                didx, selected, n_dropped = dispatch_plan(selected, lanes)
             if flt is None:
                 ok = selected
                 draw = None
@@ -1037,12 +1060,13 @@ def _make_sim(loss_fn: Callable, eval_fn: Callable, wcfg, scfg, fcfg,
             ages = jnp.where(ok > 0.0, 0, ages + 1)
             if flt is not None:
                 rel = faults.reliability_update(rel, selected, ok, flt)
-            acc = jax.lax.cond(
-                do_ev,
-                lambda p: jnp.asarray(eval_fn(p, test_x, test_labels),
-                                      jnp.float32),
-                lambda p: jnp.full((), jnp.nan, jnp.float32),
-                params)
+            with telemetry_lib.phase_scope("eval"):
+                acc = jax.lax.cond(
+                    do_ev,
+                    lambda p: jnp.asarray(eval_fn(p, test_x, test_labels),
+                                          jnp.float32),
+                    lambda p: jnp.full((), jnp.nan, jnp.float32),
+                    params)
             met = RoundMetrics(
                 accuracy=acc,
                 n_selected=jnp.sum(selected).astype(jnp.int32),
@@ -1520,7 +1544,8 @@ def run_federated_loop(
             st = _stream_advance(st, hists_r, stale, ok, cdt)
 
         if (r % eval_every) == 0 or r == fcfg.num_rounds - 1:
-            acc = float(eval_fn(params, test_x, data.test_labels))
+            with telemetry_lib.phase_scope("eval"):
+                acc = float(eval_fn(params, test_x, data.test_labels))
         else:
             acc = float("nan")
         n_sel = int(jnp.sum(selected))

@@ -360,18 +360,28 @@ class SweepEngine:
         global_start + size): ``(params, metrics[, frames])`` with a
         leading ``(size,)`` axis, from the program :meth:`run_chunk`
         folds — per-scenario values the O(R) aggregate no longer holds.
+
+        Host spans, shown by a running profiler on its host plane
+        (``jax.profiler.TraceAnnotation``): ``sweep.inputs`` holds the
+        network draw, the scenario keys, the params tiling and the
+        histograms; ``sweep.launch`` the sim's call, with its first
+        trace, lowering and compile.
         """
         data = self.data
-        indices = jnp.arange(global_start, global_start + size)
-        nets = wireless.sample_networks_indexed(
-            self._net_base, indices, data.num_devices, point.wireless)
-        keys = federated.scenario_keys(self._sim_base, global_start, size)
-        params = federated.tile_params(self.init_params, size) \
-            if self.donate_params else self.init_params
-        sim = self._sim_for(point, size)
-        return sim(params, data.images, data.labels, data.mask,
-                   data.sizes, self._hists_for(point), self._test_x,
-                   data.test_labels, nets, keys)
+        with jax.profiler.TraceAnnotation("sweep.inputs"):
+            indices = jnp.arange(global_start, global_start + size)
+            nets = wireless.sample_networks_indexed(
+                self._net_base, indices, data.num_devices, point.wireless)
+            keys = federated.scenario_keys(self._sim_base, global_start,
+                                           size)
+            params = federated.tile_params(self.init_params, size) \
+                if self.donate_params else self.init_params
+            hists = self._hists_for(point)
+        with jax.profiler.TraceAnnotation("sweep.launch"):
+            sim = self._sim_for(point, size)
+            return sim(params, data.images, data.labels, data.mask,
+                       data.sizes, hists, self._test_x, data.test_labels,
+                       nets, keys)
 
     def run_chunk(self, point: grid_lib.GridPoint, global_start: int,
                   size: int, agg):

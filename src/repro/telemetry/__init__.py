@@ -23,10 +23,18 @@ internals observable without giving up the compiled drivers:
   JSONL round-event writer + the resume-safe rewind shared with the
   sweep runner, and a run manifest), and ``python -m
   repro.telemetry.report`` renders a run summary from a JSONL log.
-* :func:`phase_scope` wraps the four driver phases — ``schedule``,
-  ``local_train``, ``aggregate``, ``stream_refresh`` — in
-  ``jax.named_scope`` so ``jax.profiler.trace`` output (see
-  ``benchmarks/run.py --profile``) attributes time to them.
+* :func:`phase_scope` wraps the driver phases (:data:`PHASES`) in
+  ``jax.named_scope("repro/<phase>")``.  The scope lands in the op
+  metadata of the compiled program, so a profiler trace attributes
+  device time to each phase; the chip benchmark reads it from there
+  (``feelbench/trace.py``).  Inside ``local_train`` the local step
+  splits into ``repro.batch`` (the minibatch draw, gather and
+  conversion) and ``repro.grad`` (forward, backward and the update);
+  the dot keeps them out of the ``repro/<phase>`` pattern, so a phase
+  keeps the time of its parts.
+* ``SweepEngine.chunk_outputs`` marks its host work with profiler
+  annotations, ``sweep.inputs`` and ``sweep.launch``, which a profile
+  shows on its host plane beside the device ops.
 
 Contracts (``tests/test_telemetry.py``): with telemetry enabled the
 *primary* outputs (params, metrics) are bitwise identical to the
@@ -38,31 +46,33 @@ observe — no extra PRNG splits, no op feeding back into the round), and
 from __future__ import annotations
 
 import dataclasses
-from typing import FrozenSet, Optional
+from typing import Optional
 
 import jax
 
-# The four profiled driver phases, in round order.  ``stream_refresh``
-# only appears in streaming runs; the other three are always present.
-PHASES = ("schedule", "local_train", "aggregate", "stream_refresh")
-
-_seen_phases: set = set()
+# The profiled driver phases.  ``stream_refresh`` only
+# appears in streaming runs; the others are always present.
+PHASES = ("schedule", "local_train", "aggregate", "stream_refresh", "eval")
 
 
 def phase_scope(name: str):
-    """``jax.named_scope`` for one driver phase, recorded for tests.
+    """``jax.named_scope("repro/<name>")`` for one driver phase.
 
     The scope is pure trace-time metadata (it names HLO ops for the
     profiler; no op changes), so the drivers enter it unconditionally —
     the ``telemetry=None`` bitwise contract is unaffected.
     """
-    _seen_phases.add(name)
     return jax.named_scope(f"repro/{name}")
 
 
-def seen_phases() -> FrozenSet[str]:
-    """Phase scopes entered since process start (test introspection)."""
-    return frozenset(_seen_phases)
+def part_scope(name: str):
+    """``jax.named_scope("repro.<name>")`` for one part of a phase.
+
+    The dot, not a slash, keeps a part out of the ``repro/<phase>``
+    pattern the trace reduction attributes device time by, so the
+    enclosing phase keeps its time and the part is read on its own.
+    """
+    return jax.named_scope(f"repro.{name}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,4 +116,4 @@ def active(cfg: Optional[TelemetryConfig]) -> Optional[TelemetryConfig]:
 
 
 __all__ = ["TelemetryConfig", "is_inert", "active", "phase_scope",
-           "seen_phases", "PHASES"]
+           "part_scope", "PHASES"]

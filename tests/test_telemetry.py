@@ -4,10 +4,13 @@ parity on telemetry leaves, the legacy loop's host-collected frames,
 JSONL sink round-trips through the report CLI, the shared rewind
 contract, and the RoundRecord sentinel fix."""
 
+import contextlib
 import dataclasses
 import functools
+import re
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -383,8 +386,101 @@ def test_reliable_edge_history_n_success(world):
         assert rec.n_success >= 0
 
 
-def test_phase_scopes_cover_all_phases(world):
+DRIVERS = {
+    "sync": {},
+    "streaming": {"stream": streaming.StreamConfig()},
+    "events": {"events": events.EventConfig(availability="churn",
+                                            buffer_size=2)},
+}
+
+
+def _sim_and_args(world, fcfg):
+    """The single-scenario sim of ``fcfg`` and its arguments."""
     kw = _run_kwargs(world)
-    fcfg = dataclasses.replace(FL, stream=streaming.StreamConfig())
-    federated.run_federated(fcfg=fcfg, **kw)
-    assert set(telemetry.PHASES) <= telemetry.seen_phases()
+    data = kw["data"]
+    sim = federated.make_feel_sim(
+        loss_fn=kw["loss_fn"], eval_fn=kw["eval_fn"], wcfg=WCFG, scfg=SCFG,
+        fcfg=fcfg, capacity=data.capacity)
+    args = (kw["init_params"], data.images, data.labels, data.mask,
+            data.sizes, federated.client_histograms(data, fcfg.num_classes),
+            synthetic.to_float(data.test_images), data.test_labels,
+            kw["net"], kw["key"])
+    return sim, args
+
+
+@pytest.mark.parametrize("driver", sorted(DRIVERS))
+def test_phase_scopes_name_the_compiled_ops(world, driver):
+    """Every scope is in the op metadata of the compiled program, where
+    the profiler reads each device op's scope path from; the gradient
+    part covers the backward pass too, inside ``local_train``."""
+    fcfg = dataclasses.replace(FL, **DRIVERS[driver])
+    sim, args = _sim_and_args(world, fcfg)
+    text = sim.lower(*args).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    want = {f"repro/{p}" for p in telemetry.PHASES
+            if p != "stream_refresh" or driver == "streaming"}
+    want |= {"repro.batch", "repro.grad"}
+    assert "repro/eval" in want
+    assert {s for s in want if any(s in n for n in names)} == want
+    assert any("repro/local_train" in n and "repro.grad/transpose(" in n
+               for n in names)
+
+
+@pytest.mark.parametrize("driver", ["sync", "events"])
+def test_scopes_change_no_value(world, driver, monkeypatch):
+    """The phase and part scopes name ops and change none: the program
+    built with them as no-ops gives bitwise the same outputs."""
+    fcfg = dataclasses.replace(FL, **DRIVERS[driver])
+    sim, args = _sim_and_args(world, fcfg)
+    scoped = jax.device_get(sim(*args))
+    monkeypatch.setattr(telemetry, "phase_scope",
+                        lambda name: contextlib.nullcontext())
+    monkeypatch.setattr(telemetry, "part_scope",
+                        lambda name: contextlib.nullcontext())
+    sim, args = _sim_and_args(world, fcfg)
+    text = sim.lower(*args).as_text(debug_info=True)
+    scopes = [f"repro/{p}" for p in telemetry.PHASES]
+    assert not [s for s in scopes + ["repro.batch", "repro.grad"]
+                if s in text]
+    assert _same_tree(scoped, jax.device_get(sim(*args)))
+
+
+def _scans(jaxpr):
+    """Every ``scan`` equation of ``jaxpr`` and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    yield from _scans(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _scans(sub)
+
+
+@pytest.mark.parametrize("driver,dispatch_cap", [
+    ("sync", None), ("sync", 3), ("events", None)])
+def test_local_train_launch_is_what_the_trainer_runs(world, driver,
+                                                     dispatch_cap):
+    """``local_train_launch`` names the trainer's shape: a scan of
+    ``max_steps`` local steps over ``lanes`` lanes — K, or the dispatch
+    cap — and no other lane count at that length."""
+    data = world[0]
+    fcfg = dataclasses.replace(FL, dispatch_cap=dispatch_cap,
+                               **DRIVERS[driver])
+    lanes, max_steps = federated.local_train_launch(
+        fcfg, data.num_devices, data.capacity)
+    assert lanes == (data.num_devices if dispatch_cap is None
+                     else dispatch_cap)
+    assert max_steps == fcfg.local_epochs * -(-data.capacity
+                                               // fcfg.batch_size)
+    sim, args = _sim_and_args(world, fcfg)
+    jaxpr = jax.make_jaxpr(sim)(*args).jaxpr
+    widths = set()
+    for eqn in _scans(jaxpr):
+        if eqn.params["length"] != max_steps:
+            continue
+        c0 = eqn.params["num_consts"]
+        carry = eqn.invars[c0:c0 + eqn.params["num_carry"]]
+        widths |= {v.aval.shape[0] for v in carry if v.aval.shape}
+    assert widths == {lanes}
