@@ -8,8 +8,8 @@ Each round:
 2. Fresh channel fading is drawn; the scheduler (``core.scheduler``)
    returns the selected set and bandwidth allocation.
 3. Selected devices run ``E`` local epochs of SGD from the global model
-   (vmapped over the *entire* client axis, masked by selection — static
-   shapes, one jit).
+   (two devices packed to a trainer lane, for the steps they need —
+   static shapes, a loop of dynamic length, one jit).
 4. The server aggregates with FedAvg weights ``|D_k| / D_r`` (Alg. 1
    line 12) — optionally through the ``fedavg_agg`` Pallas kernel path.
 5. Ages update (selected -> 0, others += 1); energy/time accumulate.
@@ -59,7 +59,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, List, Optional, Tuple
+from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -218,52 +218,130 @@ class RoundMetrics:
 
 
 # ---------------------------------------------------------------------------
-# Local training (vmapped over clients)
+# Local training (two clients packed to a lane)
 # ---------------------------------------------------------------------------
+
+class PackPlan(NamedTuple):
+    """Which two lanes each packed trainer lane runs, and for how long.
+
+    Packed lane ``j`` trains lane ``first[j]`` for ``r_first[j]`` steps,
+    then lane ``second[j]`` for ``r_second[j]``; ``trips`` is the
+    longest pair's total, the packed loop's trip count.
+    """
+
+    first: Array      # (ceil(L/2),) int32 lane indices
+    second: Array     # (ceil(L/2),)
+    r_first: Array    # (ceil(L/2),) int32 required steps
+    r_second: Array   # (ceil(L/2),)
+    trips: Array      # () int32
+
+
+def pack_plan(steps: Array) -> PackPlan:
+    """Pair the ``L`` trainer lanes by required steps ``steps`` (L,).
+
+    A stable argsort orders the lanes by steps, most first; packed lane
+    ``j`` pairs ``order[j]`` with ``order[L-1-j]``, the longest with the
+    shortest, so the pairs' totals stay close to one another.  For odd
+    ``L`` the middle lane runs alone (its partner's count is 0).  The
+    trip count is at most twice the largest count and 0 when no lane
+    needs a step.  The trainer and the chip benchmark's
+    ``packed_step_use`` both plan through this function.
+    """
+    steps = jnp.asarray(steps, jnp.int32)
+    n = steps.shape[0]
+    j = -(-n // 2)
+    order = jnp.argsort(-steps, stable=True).astype(jnp.int32)
+    first, second = order[:j], order[::-1][:j]
+    r_first, r_second = steps[first], steps[second]
+    if n % 2:
+        r_second = r_second.at[j - 1].set(0)
+    return PackPlan(first, second, r_first, r_second,
+                    jnp.max(r_first + r_second))
+
 
 def make_local_trainer(loss_fn: Callable[[Params, Array, Array, Array],
                                          Array],
                        cfg: FLConfig) -> Callable:
-    """Build the vmapped multi-epoch local-SGD update.
+    """Build the packed multi-epoch local-SGD update over ``L`` lanes.
 
-    Every client runs ``steps_k = E * ceil(size_k / B)`` gradient steps;
-    clients are padded to the max step count and masked, so one
-    ``lax.scan`` covers the heterogeneous dataset sizes (the wireless time
-    model separately charges each device for its true workload, Eq. 8).
+    ``trainer(params, images, labels, mask, active, keys)`` takes the
+    per-lane operands ``(L, cap, ...)``, the ``(L, max_steps)`` step
+    schedule ``active`` — each row a prefix of ones, ``E * ceil(size_k
+    / B)`` long, zeros for a frozen lane — and a key per lane, and
+    returns every lane's params stacked ``(L, ...)`` in lane order (the
+    wireless time model separately charges each device for its true
+    workload, Eq. 8).
+
+    Lanes are packed two to a trainer lane (:func:`pack_plan`): each of
+    the ``ceil(L/2)`` packed lanes trains one lane, then resets to the
+    global params and zero momentum and trains its partner, inside one
+    ``lax.while_loop`` of ``trips`` steps, the longest pair's total.
+    Padding neither a frozen lane nor a short lane up to ``max_steps``,
+    the loop runs the steps the schedule needs, up to the pairs'
+    imbalance.  A lane's step ``s`` draws its minibatch with
+    ``split(key_lane, max_steps)[s]``, so each lane's params are those
+    of ``max_steps`` masked steps on its own, and a lane with no step
+    returns the global params.
     """
 
-    def local_sgd(params: Params, images: Array, labels: Array,
-                  mask: Array, steps_active: Array, key: Array) -> Params:
-        cap = images.shape[0]
+    def trainer(params: Params, images: Array, labels: Array, mask: Array,
+                active: Array, keys: Array) -> Params:
+        n, max_steps = active.shape
+        cap = images.shape[1]
+        plan = pack_plan(jnp.sum(active > 0.0, axis=1))
+        step_keys = jax.vmap(lambda k: jax.random.split(k, max_steps))(keys)
+        zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
 
-        def step(carry, inp):
-            p, vel = carry
-            k, active = inp
-            with telemetry_lib.part_scope("batch"):
-                idx = jax.random.randint(k, (cfg.batch_size,), 0, cap)
-                bx = synthetic.to_float(images[idx])
-                by = labels[idx]
-                bm = mask[idx]
-            # The gradient's part holds the SGD update too: every op of
-            # the step but the minibatch's.
-            with telemetry_lib.part_scope("grad"):
-                g = jax.grad(loss_fn)(p, bx, by, bm)
-                vel = jax.tree_util.tree_map(
-                    lambda v, gi: cfg.momentum * v + gi, vel, g)
-                p_new = jax.tree_util.tree_map(
-                    lambda w, v: w - cfg.learning_rate * v, p, vel)
-                p = jax.tree_util.tree_map(
-                    lambda new, old: jnp.where(active > 0.0, new, old),
-                    p_new, p)
-            return (p, vel), None
+        def packed_lane(first, second, r_first, r_second):
+            def switch(t, p, vel, held):
+                # At step r_first the lane sets its first device's
+                # params aside and starts the second from the global
+                # model with zero momentum.
+                now = t == r_first
+                pick = lambda a, b: jax.tree_util.tree_map(
+                    lambda x, y: jnp.where(now, x, y), a, b)
+                return pick(params, p), pick(zeros, vel), pick(p, held)
 
-        vel0 = jax.tree_util.tree_map(jnp.zeros_like, params)
-        keys = jax.random.split(key, steps_active.shape[0])
-        (params, _), _ = jax.lax.scan(step, (params, vel0),
-                                      (keys, steps_active))
-        return params
+            def step(carry):
+                t, p, vel, held = carry
+                p, vel, held = switch(t, p, vel, held)
+                on_second = t >= r_first
+                dev = jnp.where(on_second, second, first)
+                k = step_keys[dev, jnp.where(on_second, t - r_first, t)]
+                with telemetry_lib.part_scope("batch"):
+                    idx = jax.random.randint(k, (cfg.batch_size,), 0, cap)
+                    bx = synthetic.to_float(images[dev, idx])
+                    by = labels[dev, idx]
+                    bm = mask[dev, idx]
+                # The gradient's part holds the SGD update too: every op
+                # of the step but the minibatch's.
+                with telemetry_lib.part_scope("grad"):
+                    g = jax.grad(loss_fn)(p, bx, by, bm)
+                    vel = jax.tree_util.tree_map(
+                        lambda v, gi: cfg.momentum * v + gi, vel, g)
+                    p = jax.tree_util.tree_map(
+                        lambda w, v: w - cfg.learning_rate * v, p, vel)
+                return t + 1, p, vel, held
 
-    return jax.vmap(local_sgd, in_axes=(None, 0, 0, 0, 0, 0))
+            # Vmapped over the packed lanes, the loop runs the longest
+            # pair's trips; a lane past its own pair's keeps its state.
+            t, p, vel, held = jax.lax.while_loop(
+                lambda c: c[0] < r_first + r_second, step,
+                (jnp.zeros((), jnp.int32), params, zeros, params))
+            # A first device whose partner needs no step is set aside
+            # here.
+            p, _, held = switch(t, p, vel, held)
+            return held, p
+
+        done_first, done_second = jax.vmap(packed_lane)(
+            plan.first, plan.second, plan.r_first, plan.r_second)
+        lanes = jnp.concatenate([plan.first, plan.second[:n // 2]])
+        back = jnp.argsort(lanes)
+        return jax.tree_util.tree_map(
+            lambda a, b: jnp.concatenate([a, b[:n // 2]])[back],
+            done_first, done_second)
+
+    return trainer
 
 
 def fedavg_aggregate(client_params: Params, weights: Array,
@@ -389,7 +467,11 @@ def _masked_local_train(trainer: Callable, max_steps: int, cfg: FLConfig,
     The single definition of the per-client step schedule and the
     ``D_k / D_r`` weight normalization — the plain and compressed round
     bodies both call it, so the scan==legacy parity contracts cannot be
-    broken by editing one copy.
+    broken by editing one copy.  The schedule is a ``(K, max_steps)``
+    mask of prefixes, ``E * ceil(size_k / B)`` steps for a selected
+    device and none for the rest; the packed trainer
+    (:func:`make_local_trainer`) runs only those steps, two devices to a
+    lane, and returns an unselected device's lane at the global model.
 
     ``dispatch_idx`` (DESIGN.md §11) switches on the dense-block path:
     the per-device operands are gathered into a ``(n_cap, ...)`` block,
@@ -556,17 +638,18 @@ def _max_local_steps(cfg: FLConfig, capacity: int) -> int:
 
 def local_train_launch(cfg: FLConfig, num_devices: int,
                        capacity: int) -> Tuple[int, int]:
-    """``(lanes, max_steps)`` of the vmapped local trainer each round.
+    """``(lanes, max_steps)``: the padded grid the step schedule is laid on.
 
-    The trainer runs ``lanes`` lanes — K, or ``min(dispatch_cap, K)`` on
-    the dense-block path — for ``max_steps`` scan steps each, whatever
-    the schedule admits: frozen lanes and a device's steps beyond its
-    own ``E * ceil(size_k / B)`` are masked, not skipped.  The drivers
-    build their trainer and dispatch plan from this, and the chip
-    benchmark's ``lane_step_use`` takes the ``lanes * max_steps``
-    lane-steps a scenario-round launches from it.  A change that skips
-    lanes or steps must change this function with it, so that the
-    count stays equal to what the program launches.
+    Each round the trainer gets ``lanes`` lanes — K, or
+    ``min(dispatch_cap, K)`` on the dense-block path — and a ``(lanes,
+    max_steps)`` schedule, a device's ``E * ceil(size_k / B)`` steps and zeros for
+    frozen lanes.  The packed trainer runs ``ceil(lanes / 2)`` lanes for
+    the longest pair's steps (:func:`pack_plan`), so ``lanes *
+    max_steps`` is the most it runs for even ``lanes``, and what it
+    runs is what the schedule needs up to the pairs' imbalance.  The
+    drivers build their schedule and dispatch plan from this; the chip
+    benchmark's ``lane_step_use`` reads required steps against this
+    grid, and its ``packed_step_use`` against what the loop runs.
     """
     lanes = num_devices if cfg.dispatch_cap is None \
         else min(int(cfg.dispatch_cap), num_devices)

@@ -382,3 +382,111 @@ def test_das_beats_random_on_noniid(small_world):
         gaps.append(accs["das"] - accs["random"])
     assert float(np.mean(gaps)) > -0.02, \
         f"DAS under-performs random: gaps={gaps}"
+
+
+# ---------------------------------------------------------------------------
+# Packed local trainer: bitwise the padded one, lane by lane
+# ---------------------------------------------------------------------------
+
+def _padded_trainer(loss_fn, cfg):
+    """The plain reference: every lane runs all ``max_steps`` steps of
+    its own masked scan, vmapped over the lanes."""
+
+    def local_sgd(params, images, labels, mask, steps_active, key):
+        cap = images.shape[0]
+
+        def step(carry, inp):
+            p, vel = carry
+            k, active = inp
+            idx = jax.random.randint(k, (cfg.batch_size,), 0, cap)
+            bx = synthetic.to_float(images[idx])
+            by = labels[idx]
+            bm = mask[idx]
+            g = jax.grad(loss_fn)(p, bx, by, bm)
+            vel = jax.tree_util.tree_map(
+                lambda v, gi: cfg.momentum * v + gi, vel, g)
+            p_new = jax.tree_util.tree_map(
+                lambda w, v: w - cfg.learning_rate * v, p, vel)
+            p = jax.tree_util.tree_map(
+                lambda new, old: jnp.where(active > 0.0, new, old),
+                p_new, p)
+            return (p, vel), None
+
+        vel0 = jax.tree_util.tree_map(jnp.zeros_like, params)
+        keys = jax.random.split(key, steps_active.shape[0])
+        (params, _), _ = jax.lax.scan(step, (params, vel0),
+                                      (keys, steps_active))
+        return params
+
+    return jax.vmap(local_sgd, in_axes=(None, 0, 0, 0, 0, 0))
+
+
+# name: (lanes, steps of each lane or None for its own size's, selected,
+# momentum)
+PACK_CASES = {
+    "unbalanced_some_unselected": (12, None, [1, 0, 1, 1, 0, 1] * 2, 0.0),
+    "odd_lanes": (7, None, [1] * 7, 0.0),
+    "nothing_selected": (12, None, [0] * 12, 0.0),
+    "all_at_max_steps": (6, "max", [1] * 6, 0.0),
+    "momentum": (9, None, [1, 1, 0, 1, 1, 1, 0, 1, 1], 0.9),
+}
+
+
+@pytest.mark.parametrize("model", ["mlp", "cnn"])
+@pytest.mark.parametrize("case", sorted(PACK_CASES))
+def test_packed_trainer_matches_padded_bitwise(small_world, case, model):
+    data = small_world[0]
+    lanes, steps, selected, momentum = PACK_CASES[case]
+    cfg = federated.FLConfig(batch_size=50, learning_rate=0.1,
+                             momentum=momentum)
+    mspec = paper_nets.PaperNetSpec(kind=model)
+    params = paper_nets.init(jax.random.key(3), mspec)
+    loss = functools.partial(paper_nets.loss_fn, spec=mspec)
+    max_steps = federated._max_local_steps(cfg, data.capacity)
+    if steps is None:
+        steps = cfg.local_epochs * np.ceil(
+            np.asarray(data.sizes[:lanes], np.float32) / cfg.batch_size)
+    elif steps == "max":
+        steps = np.full(lanes, max_steps)
+    active = (np.arange(max_steps)[None, :] < np.asarray(steps)[:, None]) \
+        * np.asarray(selected, np.float32)[:, None]
+    args = (params, data.images[:lanes], data.labels[:lanes],
+            data.mask[:lanes], jnp.asarray(active, jnp.float32),
+            jax.random.split(jax.random.key(7), lanes))
+    packed = jax.jit(federated.make_local_trainer(loss, cfg))(*args)
+    padded = jax.jit(_padded_trainer(loss, cfg))(*args)
+    for got, want, init in zip(jax.tree_util.tree_leaves(packed),
+                               jax.tree_util.tree_leaves(padded),
+                               jax.tree_util.tree_leaves(params)):
+        assert got.shape == want.shape == (lanes,) + init.shape
+        for lane in range(lanes):
+            np.testing.assert_array_equal(np.asarray(got[lane]),
+                                          np.asarray(want[lane]))
+            if not active[lane].any():
+                np.testing.assert_array_equal(np.asarray(got[lane]),
+                                              np.asarray(init))
+
+
+@pytest.mark.parametrize("lanes", [1, 2, 5, 8, 100])
+def test_pack_plan_pairs_every_lane_once(lanes):
+    rng = np.random.default_rng(lanes)
+    for draw in range(20):
+        steps = rng.integers(0, 21, lanes) * (rng.random(lanes) < 0.7)
+        if draw == 0:
+            steps[:] = 0
+        plan = jax.device_get(federated.pack_plan(jnp.asarray(steps)))
+        half = -(-lanes // 2)
+        assert plan.first.shape == plan.second.shape == (half,)
+        runs = np.concatenate([plan.first, plan.second[:lanes // 2]])
+        assert sorted(runs.tolist()) == list(range(lanes))
+        np.testing.assert_array_equal(plan.r_first, steps[plan.first])
+        want_second = steps[plan.second]
+        if lanes % 2:
+            assert want_second.shape and plan.r_second[-1] == 0
+            want_second[-1] = 0
+        np.testing.assert_array_equal(plan.r_second, want_second)
+        assert np.all(plan.r_first >= plan.r_second)
+        assert int(plan.trips) == int(np.max(plan.r_first + plan.r_second))
+        assert int(plan.trips) <= 2 * int(steps.max())
+        if not steps.any():
+            assert int(plan.trips) == 0

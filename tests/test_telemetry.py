@@ -445,27 +445,28 @@ def test_scopes_change_no_value(world, driver, monkeypatch):
     assert _same_tree(scoped, jax.device_get(sim(*args)))
 
 
-def _scans(jaxpr):
-    """Every ``scan`` equation of ``jaxpr`` and of the jaxprs inside it."""
+def _whiles(jaxpr):
+    """Every ``while`` equation of ``jaxpr`` and of the jaxprs inside it."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
+        if eqn.primitive.name == "while":
             yield eqn
         for v in eqn.params.values():
             for sub in v if isinstance(v, (tuple, list)) else (v,):
                 if isinstance(sub, jax.extend.core.ClosedJaxpr):
-                    yield from _scans(sub.jaxpr)
+                    yield from _whiles(sub.jaxpr)
                 elif isinstance(sub, jax.extend.core.Jaxpr):
-                    yield from _scans(sub)
+                    yield from _whiles(sub)
 
 
 @pytest.mark.parametrize("driver,dispatch_cap", [
     ("sync", None), ("sync", 3), ("events", None)])
 def test_local_train_launch_is_what_the_trainer_runs(world, driver,
                                                      dispatch_cap):
-    """``local_train_launch`` names the trainer's shape: a scan of
-    ``max_steps`` local steps over ``lanes`` lanes — K, or the dispatch
-    cap — and no other lane count at that length."""
-    data = world[0]
+    """``local_train_launch`` names the grid the trainer packs: ``lanes``
+    lanes — K, or the dispatch cap — of ``max_steps`` local steps.  The
+    trainer runs them two to a lane: a ``while`` loop whose carry holds
+    the params of ``ceil(lanes / 2)`` packed lanes, and no other width."""
+    data, params = world[0], world[1]
     fcfg = dataclasses.replace(FL, dispatch_cap=dispatch_cap,
                                **DRIVERS[driver])
     lanes, max_steps = federated.local_train_launch(
@@ -476,11 +477,12 @@ def test_local_train_launch_is_what_the_trainer_runs(world, driver,
                                                // fcfg.batch_size)
     sim, args = _sim_and_args(world, fcfg)
     jaxpr = jax.make_jaxpr(sim)(*args).jaxpr
+    shapes = {tuple(leaf.shape) for leaf in jax.tree_util.tree_leaves(params)}
     widths = set()
-    for eqn in _scans(jaxpr):
-        if eqn.params["length"] != max_steps:
-            continue
-        c0 = eqn.params["num_consts"]
-        carry = eqn.invars[c0:c0 + eqn.params["num_carry"]]
-        widths |= {v.aval.shape[0] for v in carry if v.aval.shape}
-    assert widths == {lanes}
+    for eqn in _whiles(jaxpr):
+        c0 = eqn.params["cond_nconsts"] + eqn.params["body_nconsts"]
+        carry = [v.aval.shape for v in eqn.invars[c0:] if v.aval.shape]
+        for w in {c[0] for c in carry}:
+            if shapes <= {c[1:] for c in carry if c[0] == w}:
+                widths.add(w)
+    assert widths == {-(-lanes // 2)}
