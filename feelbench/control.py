@@ -17,7 +17,7 @@ every number ``check.py`` compares, as a run reads the program's:
 - ``frozen``: the float32 reference with every local step returning
   its state unchanged;
 - ``halfbatch``: the float32 reference with half of every local batch
-  left out, the loss a mean over the rest.
+  left out, the loss of the model family a mean over the rest.
 
 Each must fail at least one of the cell's limits; the smallest readings
 over the seeds are the upper readings the limits in
@@ -57,11 +57,11 @@ def plain_fold(per_scenario: dict, dtype) -> dict:
 
 
 @contextlib.contextmanager
-def planted(fault: str):
-    """The reference with ``fault`` planted in its local step, or as it
-    is for any other name."""
+def planted(fault: str, cfg: dict):
+    """The reference of ``cfg`` with ``fault`` planted in its local step,
+    or as it is for any other name."""
     import jax.numpy as jnp
-    from feelbench import nets, reference
+    from feelbench import models, reference
     if fault == "frozen":
         orig = reference._local_train
 
@@ -70,13 +70,13 @@ def planted(fault: str):
                         dt)
         module, name = reference, "_local_train"
     elif fault == "halfbatch":
-        orig = nets.loss
+        module, name = models.load(cfg), "loss"
+        orig = module.loss
 
-        def patched(params, images, labels, mask, model):
+        def patched(params, x, labels, mask, cfg):
             b = mask.shape[0]
             keep = (jnp.arange(b) < b // 2).astype(mask.dtype)
-            return orig(params, images, labels, mask * keep, model)
-        module, name = nets, "loss"
+            return orig(params, x, labels, mask * keep, cfg)
     else:
         yield
         return
@@ -101,13 +101,14 @@ def control_numbers(cell: dict, seed: int, controls=("bf16",),
     """Every compared number of each control in ``controls`` for one
     seed: ``{control: {number: reading}}``."""
     import jax
-    from feelbench import check, data, nets, reference, run
+    from feelbench import check, models, reference, run
     cfg, limits = cell["cfg"], cell["limits"]
+    family = models.load(cfg)
     method = cell["traffic_mix"]["method"]
     s = cell["traffic_mix"]["scenarios_per_chunk"]
     sd = run.seeds(seed)
-    host = data.make(sd["data"], cfg)
-    params0 = jax.device_get(nets.init(jax.random.key(sd["init"]), cfg))
+    host = family.data(sd["data"], cfg)
+    params0 = jax.device_get(family.init(jax.random.key(sd["init"]), cfg))
     out = {"seeds": sd, "attempted": windows * s}
     indices = [s + i for i in check.sample(out, limits["reference_scenarios"])]
     wants = [reference.simulate(cfg, method, host, params0, sd["base"], i)
@@ -117,7 +118,7 @@ def control_numbers(cell: dict, seed: int, controls=("bf16",),
     for c in controls:
         dtype, train_dtype = PRECISION[c]
         numbers = []
-        with planted(c):
+        with planted(c, cfg):
             for index, want in zip(indices, wants):
                 got = reference.simulate(cfg, method, host, params0,
                                          sd["base"], index, dtype=dtype,

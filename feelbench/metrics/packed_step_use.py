@@ -1,8 +1,8 @@
 """packed_step_use: the share of the packed trainer's lane-steps required.
 
 Required: the local SGD steps the window's admitted devices need,
-E * ceil(size_k / B) each (``feelbench/work.py``), as in
-``lane_step_use``.  Run: the program's trainer packs its ``lanes`` lanes
+E * ceil(size_k / B) each (``feelbench/work.py``).  Run: the program's
+trainer packs its ``lanes`` lanes
 (``repro.core.federated.local_train_launch``) two to a trainer lane and
 loops for the longest pair's total steps, the trip count of
 ``repro.core.federated.pack_plan``, from which the trainer plans.  A

@@ -1,5 +1,5 @@
 """The benchmark's own copy of the MNIST-shaped generator and the shard
-partitioner: the yardstick every cell's inputs come from.
+partitioner: the yardstick the paper nets' inputs come from.
 
 Copied from the program's ``repro.data.synthetic.generate`` and
 ``repro.data.partition.partition`` so that a later change to either
@@ -97,9 +97,10 @@ def partition(images: np.ndarray, labels: np.ndarray, seed: int, *,
     ``cap`` (the program's shapes) and the same work, and only which
     device holds which size and which shards changes.
 
-    Returns numpy arrays: ``images`` (K, cap, 28, 28) uint8, ``labels``
+    Returns numpy arrays: ``images`` (K, cap, ...) uint8, ``labels``
     (K, cap) int32, ``mask`` (K, cap) float32, ``sizes`` (K,) int32,
-    ``test_images`` (T, 28, 28) uint8 and ``test_labels`` (T,) int32.
+    ``test_images`` (T, ...) uint8 and ``test_labels`` (T,) int32, where
+    ``...`` is the shape of one sample of ``images``.
     """
     n = num_shards * shard_size
     if images.shape[0] < n:
@@ -120,8 +121,7 @@ def partition(images: np.ndarray, labels: np.ndarray, seed: int, *,
                            len(train_shards), min_shards, max_shards)
     counts = counts[rng.permutation(num_devices)]
     cap = int(counts.max()) * shard_size
-    h, w = images.shape[1:]
-    out_images = np.zeros((num_devices, cap, h, w), np.uint8)
+    out_images = np.zeros((num_devices, cap) + images.shape[1:], np.uint8)
     out_labels = np.zeros((num_devices, cap), np.int32)
     out_mask = np.zeros((num_devices, cap), np.float32)
     cursor = 0

@@ -19,8 +19,10 @@ by projected gradient descent from the min-time (water-filling) start
 and the uniform start, with the iteration counts of ``sub2``.
 
 Written for one scenario at a time, round by round, with only the
-admitted devices training.  Every matmul and convolution runs at the
-configuration's ``matmul_precision``.  ``dtype`` is the precision of
+admitted devices training.  What is particular to the model (its
+inputs, loss and accuracy, how many devices train at once) comes from
+the configuration's model family (``feelbench/models``).  Every matmul
+and convolution runs at the configuration's ``matmul_precision``.  ``dtype`` is the precision of
 the whole scenario and ``train_dtype`` that of local training, FedAvg
 and evaluation alone; float32 is the reference, bfloat16 the controls.
 """
@@ -34,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from feelbench import nets
+from feelbench import models
 
 ALPHA_CEIL = 4.0
 
@@ -258,6 +260,7 @@ def _network(cfg, key, k, dt):
 
 def _local_train(cfg, params, images, labels, mask, n_steps, key, dt):
     """``n_steps`` plain SGD steps of one device (0 leaves it unchanged)."""
+    family = models.load(cfg)
     cap = images.shape[0]
     b, lr = cfg["batch_size"], cfg["learning_rate"]
     max_steps = cfg["local_epochs"] * max(1, -(-cap // b))
@@ -265,18 +268,55 @@ def _local_train(cfg, params, images, labels, mask, n_steps, key, dt):
 
     def step(j, p):
         idx = jax.random.randint(keys[j], (b,), 0, cap)
-        x = (images[idx].astype(jnp.float32) / 255.0).astype(dt)
-        g = jax.grad(nets.loss)(p, x, labels[idx], mask[idx].astype(dt),
-                                cfg["model"])
+        x = family.inputs(images[idx], dt)
+        g = jax.grad(family.loss)(p, x, labels[idx], mask[idx].astype(dt),
+                                  cfg)
         return jax.tree_util.tree_map(lambda w, gi: w - lr * gi, p, g)
 
     return jax.lax.fori_loop(0, n_steps, step, params)
+
+
+def _fedavg(cfg, params, images, labels, mask, steps, keys, wts, dt):
+    """sum_k wts_k w_k over the devices' locally trained models w_k.
+
+    The devices train ``reference_block(cfg)`` at a time, vmapped, and a
+    scan over the blocks adds up each block's weighted sum.  The devices
+    are padded to whole blocks: a padded device takes no step and has
+    weight 0.
+    """
+    k = wts.shape[0]
+    block = min(models.load(cfg).reference_block(cfg), k)
+    n = -(-k // block)
+    extra = n * block - k
+
+    def blocks(a, pad=None):
+        pad = a[:extra] if pad is None else pad
+        a = jnp.concatenate([a, pad])
+        return a.reshape((n, block) + a.shape[1:])
+
+    def part(acc, xs):
+        images, labels, mask, steps, keys, wts = xs
+        trained = jax.vmap(
+            lambda im, lb, m, s, kk: _local_train(cfg, params, im, lb, m, s,
+                                                  kk, dt)
+        )(images, labels, mask, steps, keys)
+        return jax.tree_util.tree_map(
+            lambda a, st: a + jnp.einsum("k,k...->...", wts, st),
+            acc, trained), None
+
+    xs = (blocks(images), blocks(labels), blocks(mask),
+          blocks(steps, jnp.zeros((extra,), steps.dtype)), blocks(keys),
+          blocks(wts, jnp.zeros((extra,), wts.dtype)))
+    total, _ = jax.lax.scan(part, jax.tree_util.tree_map(jnp.zeros_like,
+                                                         params), xs)
+    return total
 
 
 @functools.lru_cache(maxsize=None)
 def _round_fn(cfg_key: str, method: str, dtype: str, train_dtype: str):
     import json
     cfg = json.loads(cfg_key)
+    family = models.load(cfg)
     dt, tdt = jnp.dtype(dtype), jnp.dtype(train_dtype)
 
     @jax.jit
@@ -292,19 +332,15 @@ def _round_fn(cfg_key: str, method: str, dtype: str, train_dtype: str):
         steps = cfg["local_epochs"] * jnp.ceil(
             sizes.astype(jnp.float32) / cfg["batch_size"]).astype(jnp.int32)
         steps = jnp.where(x > 0.0, steps, 0)
-        trained = jax.vmap(
-            lambda im, lb, m, n, kk: _local_train(cfg, params, im, lb, m, n,
-                                                  kk, tdt)
-        )(images, labels, mask, steps, jax.random.split(k_train, k))
         wts = sizes.astype(dt) * x
         wts = (wts / jnp.maximum(jnp.sum(wts), 1.0)).astype(tdt)
-        avg = jax.tree_util.tree_map(
-            lambda st: jnp.einsum("k,k...->...", wts, st), trained)
+        avg = _fedavg(cfg, params, images, labels, mask, steps,
+                      jax.random.split(k_train, k), wts, tdt)
         any_sel = jnp.sum(x) > 0.0
         params = jax.tree_util.tree_map(
             lambda a, p: jnp.where(any_sel, a, p), avg, params)
         ages = jnp.where(x > 0.0, 0, ages + 1)
-        acc = nets.accuracy(params, test_x, test_labels, cfg["model"])
+        acc = family.accuracy(params, test_x, test_labels, cfg)
         return params, ages, key, (x, energy, t_round, acc, iters)
 
     return round_fn
@@ -327,6 +363,7 @@ def simulate(cfg: dict, method: str, data: dict, params0, base_seed: int,
     import json
     train_dtype = train_dtype or dtype
     dt, tdt = jnp.dtype(dtype), jnp.dtype(train_dtype)
+    family = models.load(cfg)
     fn = _round_fn(json.dumps(cfg, sort_keys=True), method, dtype,
                    train_dtype)
     k = cfg["devices"]
@@ -336,10 +373,10 @@ def simulate(cfg: dict, method: str, data: dict, params0, base_seed: int,
     labels = jnp.asarray(data["labels"])
     mask = jnp.asarray(data["mask"])
     sizes = jnp.asarray(data["sizes"])
-    hists = jnp.sum(jax.nn.one_hot(labels, 10, dtype=jnp.float32)
+    hists = jnp.sum(jax.nn.one_hot(labels, family.classes(cfg),
+                                   dtype=jnp.float32)
                     * mask[..., None], axis=1).astype(dt)
-    test_x = (jnp.asarray(data["test_images"]).astype(jnp.float32)
-              / 255.0).astype(tdt)
+    test_x = family.inputs(jnp.asarray(data["test_images"]), tdt)
     test_labels = jnp.asarray(data["test_labels"])
     params = jax.tree_util.tree_map(lambda a: jnp.asarray(a).astype(tdt),
                                     params0)

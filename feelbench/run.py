@@ -3,14 +3,16 @@
     python3 feelbench/run.py --workload cnn-das-s8 --seed 7 --seconds 30 --trace 0
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration
-(``feelbench/configs/<config>.json``: the deployment, the net, the
+(``feelbench/configs/<config>.json``: the deployment, the model, the
 wireless constants) and a traffic mix (``feelbench/traffic/<traffic>.json``:
 the scheduling method and the scenarios per chunk).  Nothing here is
 particular to one cell.
 
 1. Set-up: check the device, make the data and the initial weights from
-   ``--seed`` (``feelbench/data.py``, ``feelbench/nets.py``), build the
-   program's sweep engine over a ``scenario`` mesh of the cell's chips,
+   ``--seed`` by the configuration's model family
+   (``feelbench/models/<model>.py``), build the program's sweep engine,
+   with the family's loss and eval, over a ``scenario`` mesh of the
+   cell's chips,
    and run one chunk through it so that every program compiles or is
    read from the persistent cache.  ``setup_s`` ends here.
 2. Window: chunk after chunk of S new scenarios through
@@ -38,7 +40,6 @@ from __future__ import annotations
 import argparse
 import contextlib
 import dataclasses
-import functools
 import json
 import os
 import shutil
@@ -133,7 +134,7 @@ class Bench:
 
     cell: dict
     seeds: dict
-    data: dict            # host arrays from feelbench/data.py
+    data: dict            # host arrays from the model family
     params: object        # initial weights (device)
     engine: object
     point: object
@@ -143,6 +144,7 @@ class Bench:
 
 def program_configs(cfg: dict, method: str):
     """The program's config objects, field by field from the file."""
+    from feelbench import models
     from repro.core import bandwidth, diversity, federated, scheduler, \
         selection, wireless
     w, s, s2 = cfg["wireless"], cfg["scheduler"], cfg["sub2"]
@@ -171,7 +173,8 @@ def program_configs(cfg: dict, method: str):
     fcfg = federated.FLConfig(
         num_rounds=cfg["rounds"], local_epochs=cfg["local_epochs"],
         batch_size=cfg["batch_size"], learning_rate=cfg["learning_rate"],
-        momentum=cfg["momentum"], num_classes=cfg["net"]["classes"],
+        momentum=cfg["momentum"],
+        num_classes=models.load(cfg).classes(cfg),
         measure=s["measure"],
         index_weights=diversity.IndexWeights(*s["index_weights"]))
     return wcfg, scfg, fcfg
@@ -181,18 +184,17 @@ def build(cell: dict, seed: int, mesh=None) -> Bench:
     """Data, weights and the program's engine for one cell and seed."""
     import jax
     import jax.numpy as jnp
-    from feelbench import data as data_lib
-    from feelbench import nets
+    from feelbench import models
     from repro import sweep
     from repro.data import partition
     from repro.launch import mesh as mesh_lib
-    from repro.models import paper_nets
     from repro.sweep import engine as sweep_engine
 
     cfg, mix = cell["cfg"], cell["traffic_mix"]
+    family = models.load(cfg)
     sd = seeds(seed)
-    host = data_lib.make(sd["data"], cfg)
-    params = nets.init(jax.random.key(sd["init"]), cfg)
+    host = family.data(sd["data"], cfg)
+    params = family.init(jax.random.key(sd["init"]), cfg)
     dataset = partition.ClientDataset(
         **{k: jnp.asarray(v) for k, v in host.items()})
     wcfg, scfg, fcfg = program_configs(cfg, mix["method"])
@@ -200,18 +202,10 @@ def build(cell: dict, seed: int, mesh=None) -> Bench:
     spec = sweep.SweepSpec(fl=fcfg, sched=scfg, wireless=wcfg,
                            scenarios_per_point=s, chunk_scenarios=s,
                            base_seed=sd["base"])
-    net = cfg["net"]
-    nspec = paper_nets.PaperNetSpec(
-        kind=cfg["model"], image_size=net["image"],
-        num_classes=net["classes"], mlp_hidden=net["hidden"],
-        cnn_hidden=net["hidden"])
     if mesh is None:
         mesh = mesh_lib.make_scenario_mesh(cell["chips"])
-    engine = sweep.SweepEngine(
-        spec, data=dataset,
-        loss_fn=functools.partial(paper_nets.loss_fn, spec=nspec),
-        eval_fn=functools.partial(paper_nets.accuracy, spec=nspec),
-        init_params=params, mesh=mesh)
+    engine = sweep.SweepEngine(spec, data=dataset, init_params=params,
+                               mesh=mesh, **family.engine_args(cfg))
     fold = jax.jit(sweep_engine.aggregate_fold, static_argnums=(2,))
     return Bench(cell=cell, seeds=sd, data=host, params=params,
                  engine=engine, point=engine.points[0], fold=fold,

@@ -4,11 +4,28 @@ the program's packed trainer runs (``repro.core.federated.pack_plan``)."""
 import numpy as np
 import pytest
 
+from feelbench import run
 from feelbench.metrics import packed_step_use
-from feelbench.tests.test_bench_lanes import paper_cfg, paper_sizes
+from feelbench.models import _mnist
 from repro.core import federated
 
 CHIP = {"chips_seen": 1}
+
+
+def paper_cfg() -> dict:
+    return run.read_json(run.ROOT, "feelbench", "configs",
+                         "paper-cnn-k100.json")
+
+
+def paper_sizes(cfg: dict) -> np.ndarray:
+    """The paper deployment's samples per device (one draw of shard
+    counts from ``counts_seed``; the order of devices does not matter)."""
+    d = cfg["data"]
+    n_test = max(1, int(round(d["num_shards"] * d["test_fraction"])))
+    counts = _mnist._shard_counts(
+        np.random.default_rng(d["counts_seed"]), cfg["devices"],
+        d["num_shards"] - n_test, d["min_shards"], d["max_shards"])
+    return counts * d["shard_size"]
 
 
 def test_all_admitted_at_the_paper_deployment():

@@ -7,7 +7,7 @@ import os
 import numpy as np
 import pytest
 
-from feelbench import work
+from feelbench import models, work
 
 CONFIGS = os.path.join(os.path.dirname(__file__), "..", "configs")
 
@@ -28,13 +28,13 @@ def test_forward_flops_of_the_paper_nets():
     cnn = 2 * (24 * 24 * 10 * 25 + 8 * 8 * 20 * 250 + 320 * 50 + 50 * 10)
     mlp = 2 * (784 * 200 + 200 * 10)
     assert cnn == 961_000 and mlp == 317_600
-    assert work.forward_flops(config("paper-cnn-k100")) == cnn
-    assert work.forward_flops(MLP) == mlp
+    for cfg, want in ((config("paper-cnn-k100"), cnn), (MLP, mlp)):
+        assert models.load(cfg).forward_flops(cfg) == want
 
 
 def test_param_counts_match_the_configs():
     for cfg in (config("paper-cnn-k100"), MLP):
-        assert work.num_params(cfg) == cfg["params"]
+        assert models.load(cfg).uploaded_params(cfg) == cfg["params"]
 
 
 def test_train_steps_and_flops_at_tiny_k():

@@ -1,16 +1,16 @@
 """A real cell cut to a size the CPU runs in seconds, for the tests.
 
 The cell's configuration keeps every key; only its scale shrinks: 8
-devices, 3 rounds, 40 shards of 25 samples, 2 scenarios a chunk and
-cheaper solver loops.  Tests steer the harness with it; the program
-takes no option for it.
+devices, 3 rounds, the model family's own cut of its data
+(``cut_for_cpu``), 2 scenarios a chunk and cheaper solver loops.
+Tests steer the harness with it; the program takes no option for it.
 """
 
 from __future__ import annotations
 
 import copy
 
-from feelbench import run
+from feelbench import models, run
 
 
 def cell(name: str = "cnn-das-s8", scenarios: int = 2,
@@ -18,8 +18,7 @@ def cell(name: str = "cnn-das-s8", scenarios: int = 2,
     c = copy.deepcopy(run.load_cell(name))
     cfg = c["cfg"]
     cfg.update(devices=8, rounds=3)
-    cfg["data"].update(samples_per_class=100, num_shards=40, shard_size=25,
-                       max_shards=6)
+    models.load(cfg).cut_for_cpu(cfg)
     cfg["sub2"].update(time_bisect_iters=20, newton_iters=6, pgd_iters=30)
     c["traffic_mix"] = dict(c["traffic_mix"], scenarios_per_chunk=scenarios)
     c["chips"] = chips
